@@ -15,37 +15,24 @@ timeline simulator.  Expected replication of the paper's lesson:
 import sys
 
 import jax
-from repro.compat import make_auto_mesh
-import jax.numpy as jnp
 import numpy as np
+
+from repro.launch.mesh import make_mesh
 
 
 def main() -> int:
-    from repro.patterns import WORKLOADS, evaluate
-    mesh = make_auto_mesh((4,), ("dev",))
+    from repro.patterns import WORKLOADS, run_workload
+    mesh = make_mesh((4,), ("dev",))
     sizes = {"aes": 64 * 1024, "km": 32 * 1024, "fir": 64 * 1024,
              "sc": 512, "gd": 16 * 1024, "mt": 512, "bs": 32 * 1024}
     print("name,us_per_call,derived")
     rows = []
-    with mesh:
-        for name, mod in WORKLOADS.items():
-            args = mod.make_args(sizes[name])
-            if name == "aes":
-                plain, key, rk, sb = args
-                oracle = mod.reference(plain, key)
-                jargs = (jnp.asarray(plain), jnp.asarray(rk),
-                         jnp.asarray(sb))
-            else:
-                oracle = mod.reference(*args)
-                jargs = tuple(jnp.asarray(a) for a in args)
-            for mode, mk in [("umode", mod.make_umode),
-                             ("dmode", mod.make_dmode)]:
-                rep = evaluate(name, mod.PATTERN, mode, mk(mesh), jargs,
-                               oracle)
-                rows.append(rep)
-                print(f"{name}_{mode},{rep.sim_time_s * 1e6:.2f},"
-                      f"coll_bytes={rep.collective_bytes:.0f}"
-                      f"|pattern={rep.pattern}|correct={rep.correct}")
+    for name in WORKLOADS:
+        for rep in run_workload(name, mesh, sizes[name]):
+            rows.append(rep)
+            print(f"{name}_{rep.mode},{rep.sim_time_s * 1e6:.2f},"
+                  f"coll_bytes={rep.collective_bytes:.0f}"
+                  f"|pattern={rep.pattern}|correct={rep.correct}")
     # paper-lesson checks
     by = {(r.name, r.mode): r for r in rows}
     d_wins = sum(by[(n, "dmode")].collective_bytes

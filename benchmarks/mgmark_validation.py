@@ -14,7 +14,7 @@ the bandwidth-dominated ones).
 import sys
 
 import jax
-from repro.compat import make_auto_mesh
+from repro.launch.mesh import make_mesh
 import jax.numpy as jnp
 
 from repro.core import SINGLE_POD, SystemSpec, analyze, simulate
@@ -23,7 +23,7 @@ from repro.core.roofline import collective_sim_time
 
 def main() -> int:
     from repro.patterns import WORKLOADS
-    mesh = make_auto_mesh((4,), ("dev",))
+    mesh = make_mesh((4,), ("dev",))
     spec = SystemSpec(pod_shape=(1, 4))
     sizes = {"aes": 64 * 1024, "km": 32 * 1024, "fir": 64 * 1024,
              "sc": 512, "gd": 16 * 1024, "mt": 512, "bs": 32 * 1024}

@@ -12,32 +12,19 @@ traffic from the compiled HLO, and simulated execution time on the
 four design lessons evaluated against our numbers.
 """
 import jax
-from repro.compat import make_auto_mesh
-import jax.numpy as jnp
 import numpy as np
+
+from repro.launch.mesh import make_mesh
 
 
 def main():
-    from repro.patterns import WORKLOADS, evaluate
-    mesh = make_auto_mesh((4,), ("dev",))
+    from repro.patterns import WORKLOADS, run_workload
+    mesh = make_mesh((4,), ("dev",))
     sizes = {"aes": 64 * 1024, "km": 32 * 1024, "fir": 64 * 1024,
              "sc": 512, "gd": 16 * 1024, "mt": 512, "bs": 32 * 1024}
     rows = []
-    with mesh:
-        for name, mod in WORKLOADS.items():
-            args = mod.make_args(sizes[name])
-            if name == "aes":
-                plain, key, rk, sb = args
-                oracle = mod.reference(plain, key)
-                jargs = (jnp.asarray(plain), jnp.asarray(rk),
-                         jnp.asarray(sb))
-            else:
-                oracle = mod.reference(*args)
-                jargs = tuple(jnp.asarray(a) for a in args)
-            for mode, mk in [("umode", mod.make_umode),
-                             ("dmode", mod.make_dmode)]:
-                rows.append(evaluate(name, mod.PATTERN, mode, mk(mesh),
-                                     jargs, oracle))
+    for name in WORKLOADS:
+        rows.extend(run_workload(name, mesh, sizes[name]))
     print(f"{'workload':9s} {'pattern':12s} {'mode':6s} {'ok':3s} "
           f"{'traffic(B)':>12s} {'sim time':>10s}")
     for r in rows:

@@ -11,7 +11,6 @@
 import tempfile
 
 import jax
-from repro.compat import cost_analysis_dict
 import numpy as np
 
 from repro.core import SINGLE_POD, analyze, build_terms, simulate
@@ -63,7 +62,7 @@ def main():
         batch).compile()
     cost = analyze(compiled.as_text())
     terms = build_terms(f"{ARCH}/quickstart", "(1,1)", 1,
-                        cost_analysis_dict(compiled), cost, SINGLE_POD)
+                        compiled.cost_analysis(), cost, SINGLE_POD)
     rep = simulate(cost=cost, spec=SINGLE_POD, device_limit=1)
     print(f"flops={terms.flops_per_device:.3g} "
           f"hbm={terms.hbm_bytes_per_device:.3g}B "

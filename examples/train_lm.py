@@ -46,7 +46,7 @@ def main():
         opt_cfg=OptConfig(lr=3e-4, total_steps=args.steps,
                           warmup_steps=args.steps // 20),
         loop_cfg=LoopConfig(total_steps=args.steps, ckpt_every=100,
-                            ckpt_dir="/tmp/train_lm_ckpt", log_every=20))
+                            ckpt_dir="ckpt/train_lm", log_every=20))
     uniform = math.log(cfg.vocab_size)
     print(f"uniform baseline {uniform:.3f}; "
           f"first loss {report.losses[0]:.3f}; "

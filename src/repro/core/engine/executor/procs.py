@@ -75,6 +75,7 @@ from __future__ import annotations
 
 import multiprocessing
 import os
+import sys
 import traceback
 
 from . import rings, wire
@@ -280,6 +281,17 @@ def _worker_main(sched, wid: int, nprocs: int, child_ends, parent_ends,
         os._exit(0)
 
 
+def _holds_tpu() -> bool:
+    """True in a process whose JAX has already opened a TPU backend: a
+    TPU belongs to one process, so a forked worker could not use it."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return False
+    from jax._src import xla_bridge
+    return (xla_bridge.backends_are_initialized()
+            and jax.default_backend() == "tpu")
+
+
 class ProcExecutor(Executor):
     name = "procs"
     inline_rounds = False                 # state is shard-resident
@@ -299,6 +311,10 @@ class ProcExecutor(Executor):
 
     # -- lifecycle --------------------------------------------------------
     def prepare(self, ctxs: list) -> None:
+        if _holds_tpu():
+            raise RuntimeError(
+                "executor='procs' forks worker processes, which cannot "
+                "share this process's TPU; use executor='threads' here")
         if "fork" not in multiprocessing.get_all_start_methods():
             raise RuntimeError(
                 "executor='procs' requires the fork start method (POSIX); "

@@ -8,7 +8,9 @@ Kernels (each with a pure-jnp oracle in ref.py, wrapper in ops.py):
   * bitonic         — bitonic compare-exchange stage (MGMark BS, Irregular)
 
 All kernels target TPU (pl.pallas_call + BlockSpec VMEM tiling,
-128-aligned MXU shapes) and are validated on CPU in interpret mode.
+128-aligned MXU shapes).  Tests validate them on CPU in interpret mode;
+``chip_smoke.py`` runs them Mosaic-compiled on the chip, and
+tests/test_tpu_compile.py compiles them for a described v5e.
 """
 from . import ops, ref
 from .ops import (flash_attention, rmsnorm, ssd_chunk_kernel, ssd_pallas,

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .mode import resolve_interpret
+
 
 def _bitonic_kernel(x_ref, o_ref, *, dist: int, size: int, block: int):
     i = pl.program_id(0)
@@ -41,8 +43,7 @@ def bitonic_stage(x, dist: int, size: int, block: int = 2048,
                   interpret: bool = None):
     """One compare-exchange stage. x (L,), dist < block <= L, L % block == 0.
     ``size`` is the bitonic run length of the enclosing phase."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     L = x.shape[0]
     block = min(block, L)
     assert dist < block and L % block == 0 and block % (2 * dist) == 0

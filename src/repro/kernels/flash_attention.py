@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .mode import resolve_interpret
+
 DEFAULT_BQ = 128
 DEFAULT_BK = 128
 NEG_INF = -1e30
@@ -76,8 +78,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention(q, k, v, causal: bool = True, bq: int = DEFAULT_BQ,
                     bk: int = DEFAULT_BK, interpret: bool = None):
     """q (B,Sq,H,hd); k/v (B,Skv,K,hd); H % K == 0 -> out (B,Sq,H,hd)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     B, Sq, H, hd = q.shape
     Skv, K = k.shape[1], k.shape[2]
     G = H // K

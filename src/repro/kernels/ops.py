@@ -1,8 +1,10 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On a TPU backend the kernels compile natively; on CPU (this container)
+On a TPU backend the kernels compile natively (Mosaic); on a CPU host
 they execute in interpret mode — same kernel body, Python-evaluated —
-which is how tests validate them against the ref.py oracles.  Model code
+which is how tests validate them against the ref.py oracles.  The choice
+is made in one place, ``mode.resolve_interpret``; chip-path callers pass
+``interpret=False`` so a kernel there can never be interpreted.  Model code
 selects kernels with ``cfg.use_pallas`` / ``cfg.attn_impl``; the dry-run
 path stays pure-JAX (a TPU custom-call cannot lower for the CPU target).
 """

@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .mode import resolve_interpret
+
 
 def _rms_kernel(x_ref, w_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)
@@ -27,8 +29,7 @@ def _rms_kernel(x_ref, w_ref, o_ref, *, eps: float):
 def rmsnorm(x, w, eps: float = 1e-6, block_rows: int = 256,
             interpret: bool = None):
     """x (..., d), w (d,) -> rmsnorm(x) * w, fused."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     shape = x.shape
     d = shape[-1]
     rows = 1

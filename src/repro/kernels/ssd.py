@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .mode import resolve_interpret
+
 
 def _ssd_kernel(x_ref, dt_ref, cs_ref, b_ref, c_ref, y_ref, s_ref):
     x = x_ref[0, 0].astype(jnp.float32)       # (Q,P)
@@ -50,8 +52,7 @@ def _ssd_kernel(x_ref, dt_ref, cs_ref, b_ref, c_ref, y_ref, s_ref):
 def ssd_chunk_kernel(x, dt, cs, Bm, Cm, interpret: bool = None):
     """x (R,H,Q,P); dt/cs (R,H,Q); Bm/Cm (R,H,Q,N) ->
     (y_diag (R,H,Q,P) f32, states (R,H,N,P) f32)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     R, H, Q, P = x.shape
     N = Bm.shape[-1]
     dt2 = dt[..., None]                        # (R,H,Q,1)

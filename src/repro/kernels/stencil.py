@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .mode import resolve_interpret
+
 
 def _stencil_kernel(top_ref, mid_ref, bot_ref, k_ref, o_ref, *,
                     br: int, K: int):
@@ -45,8 +47,7 @@ def _stencil_kernel(top_ref, mid_ref, bot_ref, k_ref, o_ref, *,
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def stencil2d(img, kern, block_rows: int = 128, interpret: bool = None):
     """img (H, W), kern (K, K) -> same-padded 2-D convolution (H, W)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     H, W = img.shape
     K = kern.shape[0]
     br = min(block_rows, H)

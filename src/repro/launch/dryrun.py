@@ -23,7 +23,6 @@ import time
 import traceback
 
 import jax
-from repro.compat import cost_analysis_dict
 
 from repro.configs import ASSIGNED, SHAPES, cell_applicable, input_specs
 from repro.core import analyze, build_terms, SINGLE_POD, MULTI_POD
@@ -82,7 +81,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, verbose: bool = True):
         return row
     t_compile = time.time() - t0
     mem = compiled.memory_analysis()
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     hlo_cost = analyze(compiled.as_text())
     terms = build_terms(
         cell=f"{arch}/{shape}", mesh_name=row["mesh"], chips=row["chips"],

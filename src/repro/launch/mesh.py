@@ -15,21 +15,15 @@ import jax
 
 
 def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = 1
-    for s in shape:
-        n *= s
-    devices = jax.devices()[:n]
-    return jax.sharding.Mesh(
-        __import__("numpy").asarray(devices).reshape(shape), axes)
+    if multi_pod:
+        return make_mesh((2, 16, 16), ("pod", "data", "model"))
+    return make_mesh((16, 16), ("data", "model"))
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh over the first prod(shape) devices (tests/examples)."""
-    n = 1
-    for s in shape:
-        n *= s
-    devices = jax.devices()[:n]
-    return jax.sharding.Mesh(
-        __import__("numpy").asarray(devices).reshape(shape), axes)
+    """Mesh over the first prod(shape) devices, ordered for the physical
+    topology by ``jax.make_mesh``.  Axes are Auto: GSPMD places what the
+    programs leave unconstrained (U-mode), and shard_map bodies see
+    local views (D-mode)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

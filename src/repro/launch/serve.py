@@ -13,6 +13,7 @@ import numpy as np
 
 from repro.models import api, get_config
 from repro.serve import Engine, Request
+from .compile_cache import use_compile_cache
 
 
 def main(argv=None):
@@ -24,6 +25,7 @@ def main(argv=None):
     ap.add_argument("--max-new", type=int, default=12)
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     params = api.init(jax.random.PRNGKey(0), cfg)
     engine = Engine(cfg, params, slots=args.slots, max_seq=args.max_seq)

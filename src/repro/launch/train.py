@@ -11,12 +11,11 @@ from __future__ import annotations
 
 import argparse
 
-import jax
-
 from repro.models import get_config
 from repro.train.data import DataConfig
 from repro.train.loop import LoopConfig, run
 from repro.train.optim import OptConfig
+from .compile_cache import use_compile_cache
 from .mesh import make_mesh
 
 
@@ -28,10 +27,11 @@ def main(argv=None):
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--lr", type=float, default=1e-3)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir", default="ckpt")
     ap.add_argument("--ckpt-every", type=int, default=20)
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     d, m = (int(x) for x in args.mesh.split("x"))
     mesh = make_mesh((d, m), ("data", "model"))

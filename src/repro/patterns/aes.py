@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 PATTERN = "partitioned"
@@ -161,9 +160,9 @@ def make_umode(mesh):
 def make_dmode(mesh):
     def local(blocks, rk, sb):                       # no collectives at all
         return encrypt_blocks(blocks, rk, sb)
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P("dev", None), P(None, None), P(None)),
-                   out_specs=P("dev", None), check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P("dev", None), P(None, None), P(None)),
+                       out_specs=P("dev", None), check_vma=False)
     return jax.jit(fn)
 
 

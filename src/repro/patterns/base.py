@@ -17,7 +17,6 @@ import dataclasses
 import typing
 
 import jax
-from repro.compat import cost_analysis_dict
 import numpy as np
 
 from repro.core import SystemSpec, analyze, simulate
@@ -63,7 +62,7 @@ def evaluate(name: str, pattern: str, mode: str, jitted, args,
     cost = analyze(compiled.as_text())
     spec = spec or SystemSpec(pod_shape=(1, jax.device_count()))
     rep = simulate(cost=cost, spec=spec, device_limit=device_limit)
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     return PatternReport(
         name=name, mode=mode, pattern=pattern,
         correct=bool(err <= atol), max_err=err,

@@ -18,7 +18,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.compat import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.kernels.ref import bitonic_stage_ref, bitonic_sort_ref
@@ -82,8 +81,8 @@ def make_dmode(mesh):
             size *= 2
         return x
 
-    fn = shard_map(local, mesh=mesh, in_specs=(P("dev"),),
-                   out_specs=P("dev"), check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(P("dev"),),
+                       out_specs=P("dev"), check_vma=False)
     return jax.jit(fn)
 
 

@@ -10,7 +10,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.compat import axis_size, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 PATTERN = "adjacent"
@@ -50,15 +49,15 @@ def make_dmode(mesh):
     def local(x, taps):
         T = taps.shape[0]
         # halo: last T-1 samples of the LEFT neighbor (ring, shard 0 zero)
-        n = axis_size("dev")
+        n = jax.lax.axis_size("dev")
         idx = jax.lax.axis_index("dev")
         tail = x[-(T - 1):]
         halo = jax.lax.ppermute(tail, "dev",
                                 perm=[(i, (i + 1) % n) for i in range(n)])
         halo = jnp.where(idx == 0, jnp.zeros_like(halo), halo)
         return _fir_local(jnp.concatenate([halo, x]), taps)
-    fn = shard_map(local, mesh=mesh, in_specs=(P("dev"), P(None)),
-                   out_specs=P("dev"), check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(P("dev"), P(None)),
+                       out_specs=P("dev"), check_vma=False)
     return jax.jit(fn)
 
 

@@ -11,13 +11,20 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.compat import axis_size, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 PATTERN = "gather"
 FEATURES = 256
 STEPS = 8
 LR = 0.05
+
+
+def _grad(X, y, w, n):
+    """Least-squares gradient over the rows held, at full f32 precision
+    (the TPU's default matmul rounds f32 operands to bf16)."""
+    hi = jax.lax.Precision.HIGHEST
+    r = jnp.matmul(X, w, precision=hi) - y
+    return jnp.matmul(X.T, r, precision=hi) / n
 
 
 def reference(X: np.ndarray, y: np.ndarray, w: np.ndarray) -> np.ndarray:
@@ -40,7 +47,7 @@ def make_umode(mesh):
         X = jax.lax.with_sharding_constraint(X, sh)
 
         def step(w, _):
-            g = X.T @ (X @ w - y) / X.shape[0]
+            g = _grad(X, y, w, X.shape[0])
             return w - LR * g, None
         w, _ = jax.lax.scan(step, w, None, length=STEPS)
         return w
@@ -49,17 +56,17 @@ def make_umode(mesh):
 
 def make_dmode(mesh):
     def local(X, y, w):
-        n = X.shape[0] * axis_size("dev")
+        n = X.shape[0] * jax.lax.axis_size("dev")
 
         def step(w, _):
-            g_local = X.T @ (X @ w - y) / n
+            g_local = _grad(X, y, w, n)
             g = jax.lax.psum(g_local, "dev")         # THE gather
             return w - LR * g, None
         w, _ = jax.lax.scan(step, w, None, length=STEPS)
         return w
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P("dev", None), P("dev"), P(None)),
-                   out_specs=P(None), check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P("dev", None), P("dev"), P(None)),
+                       out_specs=P(None), check_vma=False)
     return jax.jit(fn)
 
 

@@ -11,7 +11,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.compat import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 PATTERN = "partitioned"
@@ -19,13 +18,19 @@ FEATURES = 32
 CLUSTERS = 16
 
 
+def _mm(a, b):
+    """f32 matmul at full precision: the TPU's default rounds f32 operands
+    to bf16, which flips near-tie assignments against the f32 oracle."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 def _assign_and_sum(pts, cent):
     """pts (n,F), cent (K,F) -> (sums (K,F), counts (K,), assign (n,))."""
     d2 = (jnp.sum(pts * pts, -1, keepdims=True)
-          - 2.0 * pts @ cent.T + jnp.sum(cent * cent, -1)[None])
+          - 2.0 * _mm(pts, cent.T) + jnp.sum(cent * cent, -1)[None])
     a = jnp.argmin(d2, axis=-1)
     onehot = jax.nn.one_hot(a, cent.shape[0], dtype=pts.dtype)
-    return onehot.T @ pts, jnp.sum(onehot, axis=0), a.astype(jnp.int32)
+    return _mm(onehot.T, pts), jnp.sum(onehot, axis=0), a.astype(jnp.int32)
 
 
 def reference(points: np.ndarray, centroids: np.ndarray):
@@ -62,9 +67,9 @@ def make_dmode(mesh):
         sums = jax.lax.psum(sums, "dev")
         counts = jax.lax.psum(counts, "dev")
         return sums / jnp.maximum(counts[:, None], 1)
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P("dev", None), P(None, None)),
-                   out_specs=P(None, None), check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P("dev", None), P(None, None)),
+                       out_specs=P(None, None), check_vma=False)
     return jax.jit(fn)
 
 

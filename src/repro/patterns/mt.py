@@ -12,7 +12,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.compat import axis_size, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 PATTERN = "scatter"
@@ -37,15 +36,15 @@ def make_umode(mesh):
 
 def make_dmode(mesh):
     def local(x):                                  # x (Nl, N) local rows
-        m = axis_size("dev")
+        m = jax.lax.axis_size("dev")
         Nl = x.shape[0]
         blocks = x.reshape(Nl, m, Nl).transpose(1, 0, 2)   # (m, Nl, Nl)
         recv = jax.lax.all_to_all(blocks, "dev", split_axis=0,
                                   concat_axis=0, tiled=False)
         # recv[p] = block B_pq owned by sender p; Y_q columns block p = B_pq^T
         return jnp.transpose(recv, (2, 0, 1)).reshape(Nl, m * Nl)
-    fn = shard_map(local, mesh=mesh, in_specs=(P("dev", None),),
-                   out_specs=P("dev", None), check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(P("dev", None),),
+                       out_specs=P("dev", None), check_vma=False)
     return jax.jit(fn)
 
 

@@ -10,7 +10,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.compat import axis_size, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.kernels.ref import stencil2d_ref
@@ -51,7 +50,7 @@ def make_umode(mesh):
 
 def make_dmode(mesh):
     def local(img, kern):
-        n = axis_size("dev")
+        n = jax.lax.axis_size("dev")
         idx = jax.lax.axis_index("dev")
         down = [(i, (i + 1) % n) for i in range(n)]
         up = [(i, (i - 1) % n) for i in range(n)]
@@ -61,8 +60,9 @@ def make_dmode(mesh):
         bot_halo = jnp.where(idx == n - 1, jnp.zeros_like(bot_halo), bot_halo)
         return _stencil_padded(jnp.concatenate([top_halo, img, bot_halo]),
                                kern)
-    fn = shard_map(local, mesh=mesh, in_specs=(P("dev", None), P(None, None)),
-                   out_specs=P("dev", None), check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P("dev", None), P(None, None)),
+                       out_specs=P("dev", None), check_vma=False)
     return jax.jit(fn)
 
 

@@ -30,7 +30,6 @@ import typing
 
 import jax
 import jax.numpy as jnp
-from repro.compat import axis_size, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.models import layers as L
@@ -45,7 +44,7 @@ from repro.models.base import ModelConfig
 def _tp_attention(lp, h, cfg, positions, axis: str):
     """Column-parallel QKV (head shards), row-parallel WO, one psum."""
     B, S, _ = h.shape
-    m = axis_size(axis)
+    m = jax.lax.axis_size(axis)
     Hl = cfg.num_heads // m                     # local q heads
     q = h @ lp["wq"]                            # wq: (d, q_dim/m) local
     k = h @ lp["wk"]                            # kv replicated or sharded
@@ -139,8 +138,8 @@ def tp_loss(cfg: ModelConfig, mesh: Mesh):
         return jax.lax.pmean(nll, "data")
 
     in_specs = (tp_param_specs(cfg), P("data", None), P("data", None))
-    fn = shard_map(local_loss, mesh=mesh, in_specs=in_specs,
-                   out_specs=P(), check_vma=False)
+    fn = jax.shard_map(local_loss, mesh=mesh, in_specs=in_specs,
+                       out_specs=P(), check_vma=False)
     return lambda params, batch: fn(params, batch["tokens"],
                                     batch["targets"])
 
@@ -153,7 +152,7 @@ def ep_moe_ffn(p, x, cfg: ModelConfig, axis: str = "model"):
     """Inside shard_map: x (T_local, d) local tokens; p holds the LOCAL
     expert slices (E_local = E/m on the expert axis) and a replicated
     router.  Two all_to_alls move each token to/from its experts."""
-    m = axis_size(axis)
+    m = jax.lax.axis_size(axis)
     T, d = x.shape
     E = cfg.num_experts
     El = E // m
@@ -207,7 +206,7 @@ def make_sp_decode_attention(mesh: Mesh, cfg: ModelConfig,
     "data"; returns attention(q, k_cache, v_cache, pos) -> (B,H,hd).
     Pass pos_spec=P("data") for per-slot (B,) positions."""
     def local(q, kc, vc, pos):
-        m = axis_size("model")
+        m = jax.lax.axis_size("model")
         idx = jax.lax.axis_index("model")
         Tl = kc.shape[1]
         start = idx * Tl
@@ -217,7 +216,7 @@ def make_sp_decode_attention(mesh: Mesh, cfg: ModelConfig,
             lengths = jnp.broadcast_to(lengths, (q.shape[0],))
         return sp_flash_decode_step(q, kc, vc, lengths)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("data", None, None), P("data", "model", None, None),
                   P("data", "model", None, None), pos_spec),

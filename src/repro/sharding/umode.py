@@ -64,7 +64,6 @@ def make_moe_shard_map(cfg: ModelConfig, mesh: Mesh):
     to a model-axis ALL-GATHER of every group's dispatch buffer — 16x the
     bytes of the all-to-all a discrete program writes (§Perf qwen3-moe
     iteration 2; 5.4 GB vs 0.34 GB per layer per device)."""
-    from repro.compat import shard_map
     from . import dmode
 
     def local(pl, xl):
@@ -73,10 +72,10 @@ def make_moe_shard_map(cfg: ModelConfig, mesh: Mesh):
 
     p_specs = {"router": P(None, None), "wg": P("model", None, None),
                "wu": P("model", None, None), "wd": P("model", None, None)}
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(p_specs, P(("data", "model"), None)),
-                   out_specs=(P(("data", "model"), None), P()),
-                   check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(p_specs, P(("data", "model"), None)),
+                       out_specs=(P(("data", "model"), None), P()),
+                       check_vma=False)
 
     def moe_sm(p_layer, x2d):
         return fn(p_layer, x2d)

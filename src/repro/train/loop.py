@@ -38,7 +38,7 @@ from .data import DataConfig, SyntheticLM
 class LoopConfig:
     total_steps: int = 100
     ckpt_every: int = 20
-    ckpt_dir: str = "/tmp/repro_ckpt"
+    ckpt_dir: str = "ckpt"
     async_ckpt: bool = True
     step_deadline_s: float = 60.0
     log_every: int = 10
@@ -74,8 +74,9 @@ def run(cfg: ModelConfig, mesh, data_cfg: DataConfig,
         fault_schedule: typing.Dict[int, Exception] = None,
         remesh_schedule: typing.Dict[int, typing.Any] = None,
         verbose: bool = True) -> LoopReport:
-    """Run the loop. ``fault_schedule`` injects an exception *before* the
-    given step executes (simulating a node failure mid-run);
+    """Run the loop. ``fault_schedule`` injects a failure *before* the
+    given step executes (simulating a node failure mid-run), which the
+    loop recovers from; any other exception propagates to the caller.
     ``remesh_schedule`` maps step -> new mesh (elastic shrink/grow)."""
     opt_cfg = opt_cfg or optim.OptConfig(total_steps=loop_cfg.total_steps
                                          if loop_cfg else 100)
@@ -110,27 +111,14 @@ def run(cfg: ModelConfig, mesh, data_cfg: DataConfig,
             if verbose:
                 print(f"[loop] elastic re-mesh at step {step} -> "
                       f"{dict(mesh.shape)}")
-        try:
-            if step in fault_schedule:
-                raise fault_schedule.pop(step)
-            batch = data.global_batch(step)
-            t0 = time.time()
-            state, metrics = jitted(state, batch)
-            loss = float(metrics["loss"])
-            dt = time.time() - t0
-            if dt > loop_cfg.step_deadline_s:
-                stragglers += 1
-            losses.append(loss)
-            if verbose and step % loop_cfg.log_every == 0:
-                print(f"[loop] step {step} loss {loss:.4f} "
-                      f"({dt * 1e3:.0f} ms)")
-            step += 1
-            if step % loop_cfg.ckpt_every == 0:
-                ckpt.save(step, state)
-        except Exception as e:  # noqa: BLE001 — node failure path
+        if step in fault_schedule:
+            # an injected node failure: restore the latest durable
+            # checkpoint and replay from there (step 0 if none yet).  A
+            # real error raised by the step itself is not caught here.
+            err = fault_schedule.pop(step)
             restarts += 1
             if verbose:
-                print(f"[loop] step {step} FAILED ({e}); restoring")
+                print(f"[loop] step {step} FAILED ({err}); restoring")
             ckpt.wait()
             restored, manifest = ckpt.restore(shardings=st_sh)
             if restored is None:
@@ -139,6 +127,21 @@ def run(cfg: ModelConfig, mesh, data_cfg: DataConfig,
             else:
                 state = restored
                 step = int(manifest["step"])
+            continue
+        batch = data.global_batch(step)
+        t0 = time.time()
+        state, metrics = jitted(state, batch)
+        loss = float(metrics["loss"])
+        dt = time.time() - t0
+        if dt > loop_cfg.step_deadline_s:
+            stragglers += 1
+        losses.append(loss)
+        if verbose and step % loop_cfg.log_every == 0:
+            print(f"[loop] step {step} loss {loss:.4f} "
+                  f"({dt * 1e3:.0f} ms)")
+        step += 1
+        if step % loop_cfg.ckpt_every == 0:
+            ckpt.save(step, state)
     ckpt.wait()
     return LoopReport(steps_run=len(losses), final_step=step, losses=losses,
                       restarts=restarts, remesh_events=remesh_events,

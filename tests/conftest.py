@@ -21,7 +21,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n}"
 import sys
 sys.path.insert(0, {src!r})
 import jax
-from repro.compat import make_auto_mesh
+from repro.launch.mesh import make_mesh
 """
 
 

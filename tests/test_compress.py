@@ -2,8 +2,6 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
-pytest.importorskip("hypothesis", reason="hypothesis not installed in this image")
 from hypothesis import given, settings, strategies as st
 
 from repro.train import compress
@@ -59,7 +57,7 @@ def test_compressed_psum_multidevice(run=None):
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.train import compress
-mesh = make_auto_mesh((4,), ("pod",))
+mesh = make_mesh((4,), ("pod",))
 g = jax.random.normal(jax.random.PRNGKey(0), (4, 16, 64))
 def local(gl):
     mean, err = compress.compressed_psum(gl[0], "pod")

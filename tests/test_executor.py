@@ -27,6 +27,13 @@ def test_executor_registry():
     assert make_executor(inst) is inst
 
 
+def test_procs_refuses_to_fork_a_tpu_holder(monkeypatch):
+    from repro.core.engine.executor import procs
+    monkeypatch.setattr(procs, "_holds_tpu", lambda: True)
+    with pytest.raises(RuntimeError, match="cannot share this process's TPU"):
+        ProcExecutor(max_workers=2).prepare([])
+
+
 def test_unknown_executor_raises():
     with pytest.raises(ValueError, match="unknown executor"):
         make_executor("gpu")

@@ -3,7 +3,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-pytest.importorskip("hypothesis", reason="hypothesis not installed in this image")
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref

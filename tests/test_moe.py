@@ -2,8 +2,6 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
-pytest.importorskip("hypothesis", reason="hypothesis not installed in this image")
 from hypothesis import given, settings, strategies as st
 
 from repro.models import get_config

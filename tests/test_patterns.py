@@ -29,26 +29,14 @@ def test_aes_jnp_matches_numpy_oracle():
 
 
 _PATTERN_SCRIPT = """
-import jax, jax.numpy as jnp, numpy as np
-mesh = make_auto_mesh((4,), ("dev",))
-from repro.patterns import WORKLOADS, evaluate
+mesh = make_mesh((4,), ("dev",))
+from repro.patterns import run_workload
 sizes = {{"aes": 8192, "km": 2048, "fir": 8192, "sc": 128, "gd": 2048,
          "mt": 128, "bs": 2048}}
 name = "{name}"
-mod = WORKLOADS[name]
-args = mod.make_args(sizes[name])
-with mesh:
-    if name == "aes":
-        plain, key, rk, sb = args
-        oracle = mod.reference(plain, key)
-        jargs = (jnp.asarray(plain), jnp.asarray(rk), jnp.asarray(sb))
-    else:
-        oracle = mod.reference(*args)
-        jargs = tuple(jnp.asarray(a) for a in args)
-    for mode, mk in [("umode", mod.make_umode), ("dmode", mod.make_dmode)]:
-        rep = evaluate(name, mod.PATTERN, mode, mk(mesh), jargs, oracle)
-        assert rep.correct, (name, mode, rep.max_err)
-        print(mode, "coll_bytes", rep.collective_bytes)
+for rep in run_workload(name, mesh, sizes[name]):
+    assert rep.correct, (name, rep.mode, rep.max_err)
+    print(rep.mode, "coll_bytes", rep.collective_bytes)
 print("PATTERN_OK")
 """
 

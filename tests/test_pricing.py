@@ -104,8 +104,6 @@ def test_encode_rejects_unknown_names():
 # -- hypothesis fuzz ---------------------------------------------------------
 
 def test_fuzz_parity():
-    pytest.importorskip("hypothesis",
-                        reason="hypothesis not installed in this image")
     from hypothesis import given, settings, strategies as st
 
     spec = SPECS["pod8x8x2"]

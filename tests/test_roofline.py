@@ -66,15 +66,14 @@ def test_cost_analysis_is_per_device():
     out = run_with_devices(8, """
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-mesh = make_auto_mesh((8,), ("x",))
+mesh = make_mesh((8,), ("x",))
 sh = NamedSharding(mesh, P("x", None))
 M = 1024
 a = jax.ShapeDtypeStruct((M, M), jnp.float32, sharding=sh)
 b = jax.ShapeDtypeStruct((M, M), jnp.float32,
                          sharding=NamedSharding(mesh, P(None, None)))
 comp = jax.jit(lambda x, y: x @ y).lower(a, b).compile()
-from repro.compat import cost_analysis_dict
-flops = cost_analysis_dict(comp)["flops"]
+flops = comp.cost_analysis()["flops"]
 global_flops = 2 * M**3
 ratio = flops / global_flops
 # per-device: ratio ~ 1/8; global would be ~1

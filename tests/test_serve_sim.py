@@ -149,8 +149,6 @@ def test_ledger_lowest_free_slot_first():
 
 
 def test_ledger_hypothesis_capacity_and_conservation():
-    pytest.importorskip("hypothesis",
-                        reason="hypothesis not installed in this image")
     from hypothesis import given, settings, strategies as st
 
     @settings(max_examples=60, deadline=None)
@@ -182,8 +180,6 @@ def test_ledger_hypothesis_capacity_and_conservation():
 
 
 def test_ledger_hypothesis_queue_plus_service_is_e2e():
-    pytest.importorskip("hypothesis",
-                        reason="hypothesis not installed in this image")
     from hypothesis import given, settings, strategies as st
 
     # The sim stores integer-ps stamps; queue + prefill + decode must

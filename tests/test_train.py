@@ -146,6 +146,37 @@ def test_loop_restart_after_failure(tmp_path):
     assert np.isfinite(rep.final_loss)
 
 
+def test_loop_step_error_propagates(tmp_path, monkeypatch):
+    """An error raised by the step itself is not a node failure: it leaves
+    run() at once, without a restore or a retry."""
+    from repro.launch.mesh import make_mesh
+    from repro.models import get_config
+    from repro.train import loop
+    real_build = loop.build
+    calls = []
+
+    def build(*args, **kw):
+        state, _, st_sh, batch_sh = real_build(*args, **kw)
+
+        def step(state, batch):
+            calls.append(1)
+            if len(calls) > 1:
+                pytest.fail("the step was retried after a real error")
+            raise FloatingPointError("step blew up")
+        return state, step, st_sh, batch_sh
+
+    monkeypatch.setattr(loop, "build", build)
+    cfg = get_config("qwen2-1.5b-smoke")
+    dc = DataConfig(vocab_size=cfg.vocab_size, seq_len=16, global_batch=2)
+    with pytest.raises(FloatingPointError, match="step blew up"):
+        loop.run(cfg, make_mesh((1, 1), ("data", "model")), dc,
+                 loop_cfg=loop.LoopConfig(total_steps=4, ckpt_every=2,
+                                          ckpt_dir=str(tmp_path),
+                                          async_ckpt=False),
+                 verbose=False)
+    assert len(calls) == 1
+
+
 def test_loop_elastic_remesh(tmp_path):
     from repro.launch.mesh import make_mesh
     from repro.models import get_config

@@ -10,6 +10,11 @@ The attention core has two implementations selected by
 ``cfg.attn_impl``: "ref" (einsum softmax — what the dry-run lowers; also
 the oracle) and "flash" (Pallas TPU kernel from ``repro.kernels``,
 validated in interpret mode on CPU).
+
+Each layer kind runs in a ``jax.named_scope`` (``attention`` with its cache
+write as ``kv_update``, ``mlp``, ``norm``, ``embed``, ``unembed``), so the
+compiled program's ``op_name`` metadata says which layer an operation
+belongs to.
 """
 from __future__ import annotations
 
@@ -46,6 +51,7 @@ def split_rngs(rng, n):
 # normalisation
 # --------------------------------------------------------------------------
 
+@jax.named_scope("norm")
 def rms_norm(x, weight, eps: float = 1e-6):
     dt = x.dtype
     x = x.astype(jnp.float32)
@@ -200,6 +206,7 @@ def attention_core(q, k, v, mask=None, causal: bool = False,
     return out.reshape(B, S, H, hd)
 
 
+@jax.named_scope("attention")
 def attention_block(p: Params, x, cfg, positions=None, causal=True,
                     kv_cache=None, cache_pos=None, kv_override=None):
     """Full attention block: qkv -> core -> output proj.
@@ -229,18 +236,20 @@ def attention_block(p: Params, x, cfg, positions=None, causal=True,
     T = kc.shape[1]
     cache_pos = jnp.asarray(cache_pos)
     if cache_pos.ndim == 0:
-        kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype),
-                                          (0, cache_pos, 0, 0))
-        vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype),
-                                          (0, cache_pos, 0, 0))
+        with jax.named_scope("kv_update"):
+            kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype),
+                                              (0, cache_pos, 0, 0))
+            vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype),
+                                              (0, cache_pos, 0, 0))
         valid = (jnp.arange(T) <= cache_pos + S - 1
                  )[None, None, None, None, :]
     else:
         # per-slot positions (continuous batching): vmap the row update
         upd = jax.vmap(lambda c, n, p: jax.lax.dynamic_update_slice(
             c, n, (p, 0, 0)))
-        kc = upd(kc, k.astype(kc.dtype), cache_pos)
-        vc = upd(vc, v.astype(vc.dtype), cache_pos)
+        with jax.named_scope("kv_update"):
+            kc = upd(kc, k.astype(kc.dtype), cache_pos)
+            vc = upd(vc, v.astype(vc.dtype), cache_pos)
         valid = (jnp.arange(T)[None, :] <= (cache_pos[:, None] + S - 1)
                  )[:, None, None, None, :]
     out = attention_core(q, kc, vc, mask=valid, impl="ref")
@@ -259,6 +268,7 @@ def init_swiglu(rng, cfg) -> Params:
             "wd": dense_init(rs[2], (f, d), dt)}
 
 
+@jax.named_scope("mlp")
 def swiglu(p: Params, x):
     return (jax.nn.silu(x @ p["wg"]) * (x @ p["wu"])) @ p["wd"]
 
@@ -270,6 +280,7 @@ def init_gelu_mlp(rng, cfg) -> Params:
             "w2": dense_init(rs[1], (f, d), dt)}
 
 
+@jax.named_scope("mlp")
 def gelu_mlp(p: Params, x):
     return jax.nn.gelu(x @ p["w1"]) @ p["w2"]
 
@@ -288,10 +299,12 @@ def init_embed(rng, cfg) -> Params:
     return p
 
 
+@jax.named_scope("embed")
 def embed(p: Params, tokens):
     return jnp.take(p["embed"], tokens, axis=0)
 
 
+@jax.named_scope("unembed")
 def unembed(p: Params, h, cfg):
     """Project to (padded) vocab logits; padded columns masked to -inf so
     softmax/argmax semantics are exactly the unpadded model's."""

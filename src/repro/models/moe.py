@@ -85,6 +85,7 @@ def expert_ffn(p: Params, xe):
     return jnp.einsum("ecf,efd->ecd", g * u, p["wd"])
 
 
+@jax.named_scope("moe")
 def moe_ffn(p: Params, x, cfg, ep_constraint=None):
     """Full MoE FFN on a local token block. x (T,d) -> (y (T,d), aux).
 

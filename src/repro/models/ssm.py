@@ -274,6 +274,7 @@ def init_mamba2(rng, cfg) -> Params:
     }
 
 
+@jax.named_scope("ssm")
 def mamba2_block(p: Params, x, cfg, initial_state=None,
                  return_state: bool = False, ctx=None):
     """Full-sequence Mamba2 mixer. x (B,L,d) -> y (B,L,d)."""
@@ -312,6 +313,7 @@ def bc_raw(x, p):
     return (x @ p["wbc"]).astype(jnp.float32)
 
 
+@jax.named_scope("ssm")
 def mamba2_step(p: Params, x_t, state, cfg):
     """One-token Mamba2 step. x_t (B,d); state {"ssm","conv"}."""
     B = x_t.shape[0]

@@ -10,15 +10,25 @@ rows are spliced into the slot pool.
 This is the serve-side analog of the paper's D-MGPU lesson: placement is
 explicit — each slot's KV rows live at a fixed batch index, sharded per
 sharding/specs.py, and admission never moves resident data.
+
+Under ``jax.profiler`` each phase of ``Engine.step`` writes a host span on
+the device trace's clock: ``serve.admit`` (with ``serve.prefill`` and
+``serve.splice`` per admitted request inside it), ``serve.decode`` and
+``serve.retire``.  The two compiled programs are named ``serve_decode`` and
+``serve_prefill``, so their modules read ``jit_serve_decode`` and
+``jit_serve_prefill`` in the trace.  ``stats()`` counts the work and the
+host syncs; each ``Request`` carries when it was submitted and admitted.
 """
 from __future__ import annotations
 
 import dataclasses
+import time
 import typing
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models import api
 from repro.models.base import ModelConfig
@@ -33,6 +43,8 @@ class Request:
     output: typing.List[int] = dataclasses.field(default_factory=list)
     done: bool = False
     rejected: bool = False          # prompt too long for the cache
+    t_submit: float = None          # time.perf_counter() at submit
+    t_admit: float = None           # ... at the start of its prefill
 
 
 # cache leaf -> batch axis (transformer/encdec/ssm/hybrid layouts)
@@ -66,10 +78,18 @@ class Engine:
         self._finished_early: typing.List[Request] = []
         self.steps = 0
         self.prefills = 0
-        self._decode = jax.jit(
-            lambda p, c, t: api.decode_step(p, cfg, c, t))
-        self._prefill = jax.jit(
-            lambda p, c, b: api.prefill(p, cfg, c, b))
+        self.prefill_tokens = 0     # prompt tokens prefilled
+        self.slot_steps = 0         # active slots, summed over decode steps
+        self.host_syncs = 0         # device-to-host reads
+
+        def serve_decode(p, c, t):
+            return api.decode_step(p, cfg, c, t)
+
+        def serve_prefill(p, c, b):
+            return api.prefill(p, cfg, c, b)
+
+        self._decode = jax.jit(serve_decode)
+        self._prefill = jax.jit(serve_prefill)
 
     # ------------------------------------------------------------------
     def submit(self, req: Request) -> bool:
@@ -78,6 +98,7 @@ class Engine:
         would splice/decode past row ``max_seq-1``, and jax's clamping
         ``.at[].set`` would silently corrupt the last cache row instead
         of raising."""
+        req.t_submit = time.perf_counter()
         if len(req.prompt) >= self.max_seq:
             req.rejected = True
             req.done = True
@@ -90,34 +111,45 @@ class Engine:
 
     def _admit(self) -> None:
         free = self._free_slots()
-        while free and self.queue:
-            req = self.queue.pop(0)
-            if req.max_new_tokens <= 0:
-                # nothing to generate: complete immediately, never touch
-                # a slot (previously this pinned a slot through a decode
-                # and emitted two spurious tokens)
-                req.done = True
-                self._finished_early.append(req)
-                continue
-            slot = free[0]
-            prompt = jnp.asarray(req.prompt, jnp.int32)[None]   # (1,S)
-            mini = api.init_cache(self.cfg, 1, self.max_seq)
-            logits, mini = self._prefill(self.params, mini,
-                                         {"tokens": prompt})
-            self.prefills += 1
-            self._splice(mini, slot, int(prompt.shape[1]))
-            tok = int(jnp.argmax(logits[0]))
-            req.output.append(tok)
-            if tok == self.eos or req.max_new_tokens == 1:
-                # complete at admission: the prefill token is the whole
-                # answer, so the slot stays free for the next request
-                req.done = True
-                self._finished_early.append(req)
-                continue
-            free.pop(0)
-            self.last_token = self.last_token.at[slot].set(tok)
-            self.active[slot] = req
-            self.remaining[slot] = req.max_new_tokens - 1
+        if not (free and self.queue):
+            return
+        with TraceAnnotation("serve.admit"):
+            while free and self.queue:
+                req = self.queue.pop(0)
+                if req.max_new_tokens <= 0:
+                    # nothing to generate: complete immediately, never
+                    # touch a slot (previously this pinned a slot through
+                    # a decode and emitted two spurious tokens)
+                    req.done = True
+                    self._finished_early.append(req)
+                    continue
+                slot = free[0]
+                S = len(req.prompt)
+                with TraceAnnotation("serve.prefill", uid=req.uid,
+                                     prompt_len=S):
+                    req.t_admit = time.perf_counter()
+                    prompt = jnp.asarray(req.prompt, jnp.int32)[None]
+                    mini = api.init_cache(self.cfg, 1, self.max_seq)
+                    logits, mini = self._prefill(self.params, mini,
+                                                 {"tokens": prompt})
+                    tok = int(jnp.argmax(logits[0]))
+                self.host_syncs += 1
+                self.prefills += 1
+                self.prefill_tokens += S
+                with TraceAnnotation("serve.splice", uid=req.uid):
+                    self._splice(mini, slot, S)
+                req.output.append(tok)
+                if tok == self.eos or req.max_new_tokens == 1:
+                    # complete at admission: the prefill token is the
+                    # whole answer, so the slot stays free for the next
+                    # request
+                    req.done = True
+                    self._finished_early.append(req)
+                    continue
+                free.pop(0)
+                self.last_token = self.last_token.at[slot].set(tok)
+                self.active[slot] = req
+                self.remaining[slot] = req.max_new_tokens - 1
 
     def _splice(self, mini: dict, slot: int, prompt_len: int) -> None:
         """Write the batch=1 prefill cache into slot `slot`."""
@@ -140,23 +172,28 @@ class Engine:
         done, self._finished_early = self._finished_early, []
         if not self.active:
             return done
-        logits, self.cache = self._decode(self.params, self.cache,
-                                          self.last_token)
+        batch = len(self.active)
+        with TraceAnnotation("serve.decode", batch=batch):
+            logits, self.cache = self._decode(self.params, self.cache,
+                                              self.last_token)
+            next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         self.steps += 1
-        next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        self.slot_steps += batch
         # only active slots advance; idle slots re-decode garbage rows but
         # their outputs are ignored and their pos is reset on admission
         self.last_token = next_tok
-        for slot, req in list(self.active.items()):
-            tok = int(next_tok[slot])
-            req.output.append(tok)
-            self.remaining[slot] -= 1
-            hit_cap = int(self.cache["pos"][slot]) >= self.max_seq - 1
-            if tok == self.eos or self.remaining[slot] <= 0 or hit_cap:
-                req.done = True
-                done.append(req)
-                del self.active[slot]
-                del self.remaining[slot]
+        with TraceAnnotation("serve.retire"):
+            for slot, req in list(self.active.items()):
+                tok = int(next_tok[slot])
+                req.output.append(tok)
+                self.remaining[slot] -= 1
+                hit_cap = int(self.cache["pos"][slot]) >= self.max_seq - 1
+                self.host_syncs += 2
+                if tok == self.eos or self.remaining[slot] <= 0 or hit_cap:
+                    req.done = True
+                    done.append(req)
+                    del self.active[slot]
+                    del self.remaining[slot]
         return done
 
     def run_until_drained(self, max_steps: int = 10_000
@@ -170,4 +207,7 @@ class Engine:
 
     def stats(self) -> dict:
         return {"decode_steps": self.steps, "prefills": self.prefills,
+                "prefill_tokens": self.prefill_tokens,
+                "slot_steps": self.slot_steps,
+                "host_syncs": self.host_syncs,
                 "active": len(self.active), "queued": len(self.queue)}

@@ -1,4 +1,6 @@
 """Serving engine: continuous batching correctness + slot lifecycle."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -135,3 +137,118 @@ def test_single_token_request_stops_at_prefill():
     done = eng.run_until_drained()
     assert done[0].output == ref and len(done[0].output) == 1
     assert eng.stats()["decode_steps"] == 0
+
+
+# -- instrumentation: spans, counters, stamps, named programs and scopes ------
+
+def _host_events(out_dir):
+    """(name, start_ns, end_ns, stats) of every host event the profiler
+    wrote under ``out_dir``."""
+    import pathlib
+    from jax.profiler import ProfileData
+    evs = []
+    for path in pathlib.Path(out_dir).rglob("*.xplane.pb"):
+        for plane in ProfileData.from_file(str(path)).planes:
+            if plane.name.startswith("/device:"):
+                continue
+            for line in plane.lines:
+                evs.extend((e.name, e.start_ns, e.end_ns,
+                            dict(e.stats) if e.name.startswith("serve.")
+                            else {}) for e in line.events)
+    return evs
+
+
+def test_engine_spans_nest_under_profiler(tmp_path):
+    """Each phase of a step writes its span, per phase and never per slot:
+    admit holds one prefill and one splice per admitted request (the
+    prefill carrying its uid and prompt length), and decode and retire
+    follow, all inside the caller's span."""
+    cfg = get_config("qwen2-1.5b-smoke")
+    params = api.init(RNG, cfg)
+    eng = Engine(cfg, params, slots=2, max_seq=48)
+    eng.submit(Request(uid=0, prompt=np.array([1, 2], np.int32),
+                       max_new_tokens=2))
+    eng.run_until_drained()                    # compile outside the profile
+    jax.profiler.start_trace(str(tmp_path))
+    with jax.profiler.TraceAnnotation("caller"):
+        eng.submit(Request(uid=11, prompt=np.array([4, 5, 6], np.int32),
+                           max_new_tokens=3))
+        eng.submit(Request(uid=12, prompt=np.array([7, 8], np.int32),
+                           max_new_tokens=2))
+        eng.run_until_drained()
+    jax.profiler.stop_trace()
+    evs = _host_events(tmp_path)
+    (caller,) = [e for e in evs if e[0] == "caller"]
+    serve = sorted((e for e in evs if e[0].startswith("serve.")),
+                   key=lambda e: e[1])
+    assert all(caller[1] <= e[1] and e[2] <= caller[2] for e in serve)
+
+    def named(n):
+        return [e for e in serve if e[0] == n]
+    (admit,) = named("serve.admit")
+    inside = [e for e in serve if admit[1] <= e[1] and e[2] <= admit[2]
+              and e is not admit]
+    assert [e[0] for e in inside] == ["serve.prefill", "serve.splice"] * 2
+    assert [(e[3]["uid"], e[3]["prompt_len"])
+            for e in named("serve.prefill")] == [(11, 3), (12, 2)]
+    assert [e[3]["uid"] for e in named("serve.splice")] == [11, 12]
+    # 2 decode steps: both slots, then the longer request alone
+    assert [e[3]["batch"] for e in named("serve.decode")] == [2, 1]
+    phases = [e[0] for e in serve if e[0] in
+              ("serve.admit", "serve.decode", "serve.retire")]
+    assert phases == ["serve.admit", "serve.decode", "serve.retire",
+                      "serve.decode", "serve.retire"]
+    top = [e for e in serve if e[0] != "serve.prefill"
+           and e[0] != "serve.splice"]
+    assert all(a[2] <= b[1] for a, b in zip(top, top[1:]))
+
+
+def test_engine_counters_and_stamps_exact():
+    """Two requests of known lengths on two slots: every host sync, decode
+    step, active slot and prompt token is counted, and the stamps order
+    the second admission behind the first one's prefill."""
+    cfg = get_config("qwen2-1.5b-smoke")
+    params = api.init(RNG, cfg)
+    eng = Engine(cfg, params, slots=2, max_seq=48)
+    a = Request(uid=0, prompt=np.arange(5, dtype=np.int32), max_new_tokens=4)
+    b = Request(uid=1, prompt=np.arange(3, dtype=np.int32), max_new_tokens=2)
+    big = Request(uid=2, prompt=np.arange(48, dtype=np.int32))
+    empty = Request(uid=3, prompt=np.array([1], np.int32), max_new_tokens=0)
+    for r in (a, b, big, empty):
+        eng.submit(r)
+    eng.run_until_drained()
+    # step 1: two prefills (1 read each), decode of 2 slots (2 reads per
+    # slot), b done; steps 2, 3: a alone
+    assert eng.stats() == {"decode_steps": 3, "prefills": 2,
+                           "prefill_tokens": 8, "slot_steps": 4,
+                           "host_syncs": 2 + 2 * 4, "active": 0, "queued": 0}
+    assert len(a.output) == 4 and len(b.output) == 2
+    assert a.t_submit <= b.t_submit <= a.t_admit < b.t_admit
+    assert big.rejected and big.t_submit is not None and big.t_admit is None
+    assert empty.done and empty.t_submit <= a.t_admit and \
+        empty.t_admit is None
+
+
+@pytest.mark.parametrize("arch,scopes", [
+    ("qwen2-1.5b-smoke", ("attention/kv_update", "attention", "mlp", "norm",
+                          "embed", "unembed")),
+    ("mamba2-1.3b-smoke", ("ssm", "norm", "embed", "unembed")),
+    ("qwen3-moe-30b-a3b-smoke", ("moe", "attention/kv_update", "norm")),
+])
+def test_compiled_programs_named_and_scoped(arch, scopes):
+    """The decode and prefill compile as jit_serve_decode and
+    jit_serve_prefill, and the decode's op_name metadata carries the
+    layer scopes."""
+    cfg = get_config(arch)
+    params = api.init(RNG, cfg)
+    eng = Engine(cfg, params, slots=2, max_seq=32)
+    hlo = eng._decode.lower(params, eng.cache,
+                            eng.last_token).compile().as_text()
+    assert hlo.startswith("HloModule jit_serve_decode")
+    names = set(re.findall(r'op_name="jit\(serve_decode\)/([^"]*)"', hlo))
+    for s in scopes:
+        assert any(f"/{s}/" in "/" + n for n in names), s
+    mini = api.init_cache(cfg, 1, 32)
+    pre = eng._prefill.lower(params, mini,
+                             {"tokens": jnp.zeros((1, 4), jnp.int32)})
+    assert pre.as_text().startswith("module @jit_serve_prefill")

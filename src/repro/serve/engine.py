@@ -5,7 +5,9 @@ they arrive, every engine iteration runs ONE batched decode step across
 all active slots (per-slot positions — see layers.attention_block's
 vmap'd cache update), and finished slots are freed immediately for the
 next waiting request.  Prefill runs per-request (batch=1) and its cache
-rows are spliced into the slot pool.
+rows are spliced into the slot pool.  Retiring brings every slot's new
+token and position to the host in one read, so a decode step costs one
+host sync whatever the batch.
 
 This is the serve-side analog of the paper's D-MGPU lesson: placement is
 explicit — each slot's KV rows live at a fixed batch index, sharded per
@@ -183,12 +185,14 @@ class Engine:
         # their outputs are ignored and their pos is reset on admission
         self.last_token = next_tok
         with TraceAnnotation("serve.retire"):
+            # one read for the whole batch: every slot's token and position
+            toks, pos = jax.device_get((next_tok, self.cache["pos"]))
+            self.host_syncs += 1
             for slot, req in list(self.active.items()):
-                tok = int(next_tok[slot])
+                tok = int(toks[slot])
                 req.output.append(tok)
                 self.remaining[slot] -= 1
-                hit_cap = int(self.cache["pos"][slot]) >= self.max_seq - 1
-                self.host_syncs += 2
+                hit_cap = pos[slot] >= self.max_seq - 1
                 if tok == self.eos or self.remaining[slot] <= 0 or hit_cap:
                     req.done = True
                     done.append(req)
@@ -206,6 +210,10 @@ class Engine:
         return out
 
     def stats(self) -> dict:
+        """Counters since the engine was made.  ``host_syncs`` counts
+        device-to-host reads: one per admitted request (its prefill token)
+        and one per decode step (every slot's token and position together),
+        whatever the batch."""
         return {"decode_steps": self.steps, "prefills": self.prefills,
                 "prefill_tokens": self.prefill_tokens,
                 "slot_steps": self.slot_steps,

@@ -217,16 +217,120 @@ def test_engine_counters_and_stamps_exact():
     for r in (a, b, big, empty):
         eng.submit(r)
     eng.run_until_drained()
-    # step 1: two prefills (1 read each), decode of 2 slots (2 reads per
-    # slot), b done; steps 2, 3: a alone
+    # step 1: two prefills (1 read each), decode of 2 slots (1 read for
+    # the batch), b done; steps 2, 3: a alone (1 read each)
     assert eng.stats() == {"decode_steps": 3, "prefills": 2,
                            "prefill_tokens": 8, "slot_steps": 4,
-                           "host_syncs": 2 + 2 * 4, "active": 0, "queued": 0}
+                           "host_syncs": 2 + 3, "active": 0, "queued": 0}
     assert len(a.output) == 4 and len(b.output) == 2
     assert a.t_submit <= b.t_submit <= a.t_admit < b.t_admit
     assert big.rejected and big.t_submit is not None and big.t_admit is None
     assert empty.done and empty.t_submit <= a.t_admit and \
         empty.t_admit is None
+
+
+class _ReadSpy:
+    """Counts the host's reads of device arrays: ``jax.device_get`` calls,
+    and scalar conversions of a ``jax.Array`` (``int(x[slot])``, say)."""
+
+    SCALAR = ("__int__", "__index__", "__float__", "__bool__", "item")
+
+    def __init__(self, monkeypatch):
+        array_type = type(jnp.zeros(()))
+        self.device_gets = self.scalars = 0
+        get = jax.device_get
+
+        def device_get(x):
+            self.device_gets += 1
+            return get(x)
+        monkeypatch.setattr(jax, "device_get", device_get)
+        for name in self.SCALAR:
+            monkeypatch.setattr(array_type, name,
+                                self._counted(getattr(array_type, name)))
+
+    def _counted(self, method):
+        def wrapper(arr, *a, **k):
+            self.scalars += 1
+            return method(arr, *a, **k)
+        return wrapper
+
+
+@pytest.mark.parametrize("active", [1, 3])
+def test_host_reads_per_step_do_not_grow_with_batch(active, monkeypatch):
+    """Retiring reads every slot's token and position in one transfer: a
+    decode step costs one host read at 1 active slot and at 3, and no
+    read is made per slot."""
+    cfg = get_config("qwen2-1.5b-smoke")
+    params = api.init(RNG, cfg)
+    eng = Engine(cfg, params, slots=3, max_seq=48)
+    eng.submit(Request(uid=-1, prompt=np.array([1, 2], np.int32),
+                       max_new_tokens=2))
+    eng.run_until_drained()                  # compile outside the count
+    before = eng.stats()
+    spy = _ReadSpy(monkeypatch)
+    for i in range(active):
+        eng.submit(Request(uid=i, prompt=np.array([3, 4 + i], np.int32),
+                           max_new_tokens=5))
+    done = eng.run_until_drained()
+    after = eng.stats()
+    assert sorted(r.uid for r in done) == list(range(active))
+    assert all(len(r.output) == 5 for r in done)
+    steps = after["decode_steps"] - before["decode_steps"]
+    prefills = after["prefills"] - before["prefills"]
+    assert (steps, prefills) == (4, active)
+    assert after["slot_steps"] - before["slot_steps"] == 4 * active
+    assert after["host_syncs"] - before["host_syncs"] - prefills == steps
+    # one device_get a step; the only scalar reads are the prefill tokens
+    assert spy.device_gets == steps
+    assert spy.scalars == prefills
+
+
+def _engine_cut(ref, eos, max_new, prompt_len, max_seq):
+    """Greedy tokens ``ref`` cut where the engine stops a request: at its
+    first EOS, at ``max_new`` tokens, or at the token decoded at position
+    ``max_seq - 1`` (the prefill token, ``ref[0]``, is never cut by it)."""
+    out = []
+    for k, tok in enumerate(ref):
+        out.append(tok)
+        if tok == eos or len(out) >= max_new or \
+                (k >= 1 and prompt_len + k >= max_seq - 1):
+            return out
+    raise AssertionError("the reference ends before any stop")
+
+
+@pytest.mark.parametrize("arch", ["qwen2-1.5b-smoke", "mamba2-1.3b-smoke"])
+def test_three_stops_in_one_step_exact(arch):
+    """Three slots finish in the same decode step, each for its own reason
+    (EOS, ``max_new_tokens``, the ``max_seq - 1`` cap), and each answer is
+    the greedy reference cut there."""
+    cfg = get_config(arch)
+    params = api.init(RNG, cfg)
+    M, J = 16, 3                   # all three stop at decode step J
+    pa, pb = np.array([5, 6, 7, 8], np.int32), np.array([3, 1, 4], np.int32)
+    pc = np.arange(20, 20 + M - 1 - J, dtype=np.int32)   # cap at step J
+    ref_a = _greedy_reference(cfg, params, pa, J + 3, max_seq=M)
+    ref_b = _greedy_reference(cfg, params, pb, J + 1, max_seq=M)
+    ref_c = _greedy_reference(cfg, params, pc, J + 1, max_seq=M)
+    eos = ref_a[J]                 # emitted mid-answer by a, by no other
+    assert eos not in ref_a[:J] + ref_b + ref_c
+    want = {0: _engine_cut(ref_a, eos, J + 3, len(pa), M),
+            1: _engine_cut(ref_b, eos, J + 1, len(pb), M),
+            2: _engine_cut(ref_c, eos, 100, len(pc), M)}
+    assert [len(w) for w in want.values()] == [J + 1] * 3
+
+    eng = Engine(cfg, params, slots=3, max_seq=M, eos_token=eos)
+    for uid, (p, n) in enumerate([(pa, J + 3), (pb, J + 1), (pc, 100)]):
+        eng.submit(Request(uid=uid, prompt=p, max_new_tokens=n))
+    per_step = [eng.step() for _ in range(J)]
+    assert [[r.uid for r in d] for d in per_step[:-1]] == [[]] * (J - 1)
+    got = {r.uid: r for r in per_step[-1]}
+    assert sorted(got) == [0, 1, 2] and eng.stats()["active"] == 0
+    assert {u: r.output for u, r in got.items()} == want
+    a, b, c = got[0], got[1], got[2]
+    assert a.output[-1] == eos and len(a.output) < a.max_new_tokens
+    assert eos not in b.output and len(b.output) == b.max_new_tokens
+    assert eos not in c.output and len(c.output) == M - len(pc) \
+        < c.max_new_tokens
 
 
 @pytest.mark.parametrize("arch,scopes", [

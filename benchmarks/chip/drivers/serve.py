@@ -19,6 +19,7 @@ how far the token's logit lies below the reference's best
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import gc
 import time
@@ -262,6 +263,18 @@ def bad_outputs(recs, vocab: int) -> int:
                    or any(not 0 <= t < vocab for t in r.req.output)))
 
 
+def reduce_traced(engine: Engine, trace: tracing.Trace,
+                  chips: typing.List[int]) -> typing.Tuple[dict, float]:
+    """The traced span's reduction, with the decode's operations mapped to
+    their scopes through its compiled program, and the seconds that
+    program's HLO took to come back (a compile-cache hit)."""
+    t = clock()
+    hlo = engine._decode.lower(engine.params, engine.cache,
+                               engine.last_token).compile().as_text()
+    hlo_s = clock() - t
+    return tracing.reduce(trace, chips, tracing.scopes_from_hlo(hlo)), hlo_s
+
+
 # --------------------------------------------------------------------------
 
 def setup(cell: common.Cell, seed: int):
@@ -277,8 +290,9 @@ def run(cell: common.Cell, seed: int, seconds: float, trace: bool,
         t_start: float, trace_dir) -> dict:
     cfg, params, engine = setup(cell, seed)
     arrivals = gen.make_arrivals(cell.traffic, seconds, seed, cfg.vocab_size)
-    tracer = tracing.Capture(trace_dir, trace)
-    with common.CompileEvents() as compiles:
+    tracer = tracing.Capture(trace_dir, trace, counters=engine.stats)
+    gcs = tracing.GcSpans() if trace else contextlib.nullcontext()
+    with common.CompileEvents() as compiles, gcs:
         w = play(engine, arrivals, seconds, gen.lead_in_s(cell.traffic),
                  tracer)
     setup_s = w.t_begin - t_start
@@ -287,10 +301,13 @@ def run(cell: common.Cell, seed: int, seconds: float, trace: bool,
     lat = latencies(w)
     c = cell.config
     record = types.SimpleNamespace(
-        cell=cell, window=w, latency=lat, chips=cell.chips,
-        trace=(tracing.reduce(tracer.trace, [d.id for d in devices])
-               if tracer.trace else None),
+        cell=cell, window=w, latency=lat, chips=cell.chips, trace=None,
         served_flops=served_flops(cell.counts, c, w.served))
+    if tracer.trace:
+        record.trace, hlo_s = reduce_traced(engine, tracer.trace,
+                                            [d.id for d in devices])
+        record.engine = tracer.counters_delta()
+        record.gc = gcs.summary(w.t0, w.t_stop)
     notes = {
         "window": {"window_s": w.window_s, "lead_in_s": w.t0 - w.t_begin,
                    "lead_in_arrived": len(w.lead),
@@ -314,6 +331,13 @@ def run(cell: common.Cell, seed: int, seconds: float, trace: bool,
                                "compiled": compiles.compiled,
                                "cache_hits": compiles.cache_hits},
     }
+    if record.trace:
+        notes["trace_events"] = {
+            "device_ops": record.trace["op_count"],
+            "device_modules": sum(map(len, tracer.trace.modules.values())),
+            "host_spans": len(tracer.trace.spans)}
+        notes["trace"] = dict(record.trace, engine=record.engine,
+                              gc=record.gc, decode_hlo_s=hlo_s)
 
     # correctness: free the engine's state, then run the reference
     picked = sample(w.served, cell.traffic["check"]["sample"], seed)

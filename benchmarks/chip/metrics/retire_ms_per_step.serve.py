@@ -1,4 +1,4 @@
-"""Self time of the ``serve.retire`` spans (the per-slot host reads of each
+"""Self time of the ``serve.retire`` spans (the host read of every slot's
 new token and position, and retiring) per ``serve.decode`` span in the
 traced span, in ms.  Layer: serving scheduler."""
 

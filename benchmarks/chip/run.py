@@ -97,8 +97,6 @@ def main(argv=None) -> int:
     line = result_line(cell, dev, res, bool(args.trace))
     for k, v in res["notes"].items():
         print(f"{k}: {json.dumps(v)}", file=sys.stderr)
-    if line["device"].get("busy_s") is not None:
-        print("trace: " + json.dumps(res["record"].trace), file=sys.stderr)
     for k, c in line["checks"].items():
         print(f"check {k}: {c['value']!r} limit {c['limit']!r}",
               file=sys.stderr)

@@ -1,6 +1,7 @@
 """The attribution of a traced serving run to the program's spans, programs
-and scopes, on a hand-built trace; the readers of its per-layer numbers on
-a hand-built record; and the tool's loop on a tiny cell on the CPU."""
+and scopes (``tracing.reduce``), on a hand-built trace; the readers of its
+per-layer numbers on a hand-built record; and a traced run of a tiny cell
+on the CPU."""
 import gc
 import json
 import pathlib
@@ -14,7 +15,7 @@ sys.path.insert(0, str(REPO))
 sys.path.insert(0, str(REPO / "src"))
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent))
 import chipbench_tiny  # noqa: E402
-from benchmarks.chip import attribution, common, tracing  # noqa: E402
+from benchmarks.chip import common, tracing  # noqa: E402
 from repro.serve import Request  # noqa: E402
 
 HLO = """HloModule jit_serve_decode, is_scheduled=true
@@ -40,7 +41,7 @@ metadata={op_name="jit(serve_decode)/while/body/closed_call/mlp/jit(silu)/mul"}
 """.replace("\\\n", "")
 
 
-def trace(program: bool = True) -> attribution.ProgramTrace:
+def trace(program: bool = True) -> tracing.Trace:
     """Traced span 10..20 s on one chip.  A step 10..16: admission (one
     prefill, one splice), decode, retire (a collection inside it); then the
     generator waits, and the next batch is read.  The device runs the
@@ -68,17 +69,25 @@ def trace(program: bool = True) -> attribution.ProgramTrace:
                   ("serve.decode", 12.0, 12.5, {"batch": 2}),
                   ("serve.retire", 12.5, 16.0, {}),
                   ("host.gc", 13.0, 13.5, {"generation": 2})]
-    return attribution.ProgramTrace(ops={0: ops}, spans=spans,
-                                    modules={0: modules if program else []})
+    return tracing.Trace(ops={0: ops}, spans=spans,
+                         modules={0: modules if program else []})
+
+
+# the keys the reduction had before it read the program's spans, programs
+# and scopes
+BENCHMARK_KEYS = ("op_count", "window_s", "steps", "busy_s", "idle_share",
+                  "collective_exposed_s", "device_ops", "idle_gaps",
+                  "ops_from_to_s", "span_s")
 
 
 def test_benchmark_keys_unchanged_by_program_spans():
-    """tracing.reduce reads the same with and without the program's spans
-    and module lines; only its idle-gap labels name the innermost span."""
-    bare = tracing.reduce(trace(program=False).benchmark_view(), [0])
-    full = tracing.reduce(trace().benchmark_view(), [0])
-    assert bare.keys() == full.keys()
-    for k in bare:
+    """tracing.reduce's driver-span keys read the same with and without the
+    program's spans and module lines; only its idle-gap labels name the
+    innermost span."""
+    bare = tracing.reduce(trace(program=False), [0])
+    full = tracing.reduce(trace(), [0])
+    assert bare.keys() == full.keys() >= set(BENCHMARK_KEYS)
+    for k in BENCHMARK_KEYS:
         if k != "idle_gaps":
             assert bare[k] == full[k], k
     assert [d for _, d in bare["idle_gaps"]] == \
@@ -94,7 +103,7 @@ def test_benchmark_keys_unchanged_by_program_spans():
 
 
 def test_reduce_spans_idle_modules():
-    s = attribution.reduce(trace(), [0])
+    s = tracing.reduce(trace(), [0])
     assert s["span_self_s"] == pytest.approx({
         "engine.step": 0.0, "serve.admit": 0.5, "serve.prefill": 1.0,
         "serve.splice": 0.5, "serve.decode": 0.5, "serve.retire": 3.0,
@@ -108,7 +117,7 @@ def test_reduce_spans_idle_modules():
         "serve.prefill": 0.2, "serve.splice": 0.2, "serve.admit": 0.4,
         "serve.decode": 0.2, "serve.retire": 1.8, "generator wait": 2.0,
         "next batch": 1.0, "no span": 1.0})
-    busy = tracing.reduce(trace().benchmark_view(), [0])["busy_s"]
+    busy = s["busy_s"]
     assert sum(s["idle_by_span_s"].values()) == pytest.approx(10.0 - busy)
     assert s["module_s"] == pytest.approx({
         "jit_serve_prefill": 0.8, "jit_scatter": 0.4, "jit_serve_decode": 2.0})
@@ -121,13 +130,13 @@ def test_scope_self_time_over_nested_while():
     instruction: a while counts the time its body's ops leave, an op with
     no op_name takes its loop's scope, the prefill's op of the same name
     does not count, and the scopes add up to the decode's device time."""
-    scopes = attribution.scopes_from_hlo(HLO)
+    scopes = tracing.scopes_from_hlo(HLO)
     assert scopes["%while.16"] == "(no scope)"
     assert scopes["%fusion.145"] == "attention"
     assert scopes["%while.17"] == scopes["%scatter.3"] == "attention/kv_update"
     assert scopes["%fusion.1"] == "mlp" and "%copy.2" not in scopes
     assert "%fusion.148" not in scopes
-    s = attribution.reduce(trace(), [0], scopes)
+    s = tracing.reduce(trace(), [0], scopes)
     assert s["scope_s"] == pytest.approx({
         "(no scope)": 0.2 + 0.8, "attention": 0.4,
         "attention/kv_update": 0.14 + 0.06 + 0.2, "mlp": 0.2})
@@ -138,14 +147,14 @@ def test_scope_self_time_over_nested_while():
 def test_scopes_of_compiled_decode():
     """On a decode compiled here: the named scopes, and JAX's own parts
     left out."""
-    assert attribution.scope_of(
+    assert tracing.scope_of(
         "jit(serve_decode)/while/body/closed_call/attention/kv_update/"
         "vmap(vmap())/scatter") == "attention/kv_update"
-    assert attribution.scope_of("jit(serve_decode)/while/body/squeeze") == \
+    assert tracing.scope_of("jit(serve_decode)/while/body/squeeze") == \
         "(no scope)"
-    assert attribution.scope_of("jit(serve_decode)/embed/jit(_take)/lt") == \
+    assert tracing.scope_of("jit(serve_decode)/embed/jit(_take)/lt") == \
         "embed"
-    assert attribution.scope_of(
+    assert tracing.scope_of(
         "jit(serve_prefill)/while/body/closed_call/ssm/transpose;"
         "ssm/bcqn,bcqh->bchnp/transpose") == "ssm"
     import jax
@@ -156,13 +165,13 @@ def test_scopes_of_compiled_decode():
     eng = Engine(cfg, params, slots=2, max_seq=32)
     hlo = eng._decode.lower(params, eng.cache,
                             eng.last_token).compile().as_text()
-    found = set(attribution.scopes_from_hlo(hlo).values())
+    found = set(tracing.scopes_from_hlo(hlo).values())
     assert {"attention", "attention/kv_update", "mlp", "norm", "embed",
             "unembed", "(no scope)"} <= found
 
 
 def test_gc_spans_time_collections():
-    with attribution.GcSpans() as g:
+    with tracing.GcSpans() as g:
         gc.collect()
     assert g._callback not in gc.callbacks
     s = g.summary(float("-inf"), float("inf"))
@@ -178,7 +187,7 @@ def test_gc_spans_time_collections():
 def record():
     """The hand-built trace's reduction, counters of four decode steps, and
     four requests of the window: two admitted, one waiting, one refused."""
-    s = attribution.reduce(trace(), [0], attribution.scopes_from_hlo(HLO))
+    s = tracing.reduce(trace(), [0], tracing.scopes_from_hlo(HLO))
 
     def req(t_submit, t_admit, rejected=False):
         r = Request(uid=0, prompt=None, rejected=rejected)
@@ -202,7 +211,10 @@ EXPECTED = {
     "host_syncs_per_step.serve": 13 / 4,
     "batch_per_step.serve": 6 / 4,
     "attention_dev_ms_per_step.decode": 800.0,
+    # the layer loop's 0.2 s and the cache slice's 0.8 s lie in no scope
+    "stack_dev_ms_per_step.decode": 1000.0,
 }
+READERS = tuple(EXPECTED)
 
 
 def reader(name):
@@ -210,17 +222,23 @@ def reader(name):
                               / f"{name}.py")
 
 
-@pytest.mark.parametrize("name", attribution.READERS)
+@pytest.mark.parametrize("name", READERS)
 def test_reader_on_hand_built_record(name):
-    assert set(EXPECTED) == set(attribution.READERS)
+    """Every listed metric that reads the program's spans or counters is
+    checked here, and each reader here is listed."""
+    listed = {m["name"]: m["source"] for m in json.loads(
+        (REPO / "BENCHMARK.json").read_text())["per_layer"]}
+    assert set(EXPECTED) <= set(listed)
+    assert {k for k, v in listed.items()
+            if v in ("program_span", "program_counter")} <= set(EXPECTED)
     assert reader(name).read(record()) == pytest.approx(EXPECTED[name])
 
 
-@pytest.mark.parametrize("name", attribution.READERS)
+@pytest.mark.parametrize("name", READERS)
 def test_reader_finds_nothing(name):
     """A record from a program without the spans, programs, counters and
     stamps, as `drivers/serve.py` records it: None, never 0."""
-    bare = tracing.reduce(trace(program=False).benchmark_view(), [0])
+    bare = tracing.reduce(trace(program=False), [0])
     old = types.SimpleNamespace(
         trace=bare, window=types.SimpleNamespace(
             recs=[types.SimpleNamespace(req=types.SimpleNamespace(
@@ -232,33 +250,45 @@ def test_reader_finds_nothing(name):
 
 
 # --------------------------------------------------------------------------
-# the tool on a tiny cell
+# a traced run of a tiny cell
 # --------------------------------------------------------------------------
 
 def test_tool_on_tiny_cell(monkeypatch, tmp_path, capsys):
+    """run.py --trace 1 reads the program's spans and counters: its stderr
+    ``trace`` line holds the whole reduction, the engine's counters over the
+    traced span, the window's collections and the decode's HLO time."""
     root = chipbench_tiny.make_root(tmp_path)
     monkeypatch.setattr(common, "HERE", root)
     monkeypatch.setattr(common, "ROOT", tmp_path)
     monkeypatch.setattr(common, "PLATFORM", "cpu")
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
-    monkeypatch.setattr(attribution, "TRACE_DIR", tmp_path / "trace")
-    out = tmp_path / "out" / "a.json"
-    rc = attribution.main(["--workload", "tiny-qwen2.chat", "--seed",
-                           "3000000001", "--seconds", "2", "--out", str(out)])
+    run = common.load_module(REPO / "benchmarks" / "chip" / "run.py")
+    monkeypatch.setattr(run, "TRACE_DIR", tmp_path / "trace")
+    rc = run.main(["--workload", "tiny-qwen2.chat", "--seed", "3000000001",
+                   "--seconds", "2", "--trace", "1"])
     assert rc == 0
-    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert json.loads(out.read_text()) == line
-    m, e, t = line["metrics"], line["engine"], line["trace"]
+    out, err = capsys.readouterr()
+    line = json.loads(out.strip().splitlines()[-1])
+    notes = dict(l.split(": ", 1) for l in err.splitlines() if ": {" in l)
+    t = json.loads(notes["trace"])
+    m, e = line["metrics"], t["engine"]
+    assert json.loads(notes["trace_events"])["device_ops"] == t["op_count"]
     # the CPU has no device plane: device numbers are missing, not 0
     for name in ("prefill_dev_ms.serve", "decode_dev_ms_per_step.serve",
-                 "attention_dev_ms_per_step.decode"):
-        assert m[name] is None
-    assert m["engine_queue_wait_p95_ms"] >= 0
-    assert m["batch_per_step.serve"] == e["slot_steps"] / e["decode_steps"]
-    # two reads per active slot per step, one per admission
-    assert e["host_syncs"] == 2 * e["slot_steps"] + e["prefills"]
+                 "attention_dev_ms_per_step.decode",
+                 "stack_dev_ms_per_step.decode"):
+        assert name not in m
+    assert m["engine_queue_wait_p95_ms"]["value"] >= 0
+    assert m["batch_per_step.serve"]["value"] == pytest.approx(
+        e["slot_steps"] / e["decode_steps"])
+    # one read per decode step, whatever the batch, and one per admission
+    assert e["host_syncs"] == e["decode_steps"] + e["prefills"]
+    assert m["host_syncs_per_step.serve"]["value"] == pytest.approx(
+        e["host_syncs"] / e["decode_steps"])
     assert t["span_n"]["serve.decode"] == e["decode_steps"] == t["steps"]
     assert t["span_n"]["serve.prefill"] == e["prefills"]
-    assert m["retire_ms_per_step.serve"] > 0
+    assert m["retire_ms_per_step.serve"]["value"] > 0
     assert sum(t["idle_by_span_s"].values()) == pytest.approx(t["window_s"])
-    assert line["gc"]["collections"] >= 0 and line["out_tok_s"] > 0
+    assert t["gc"]["collections"] >= 0 and t["decode_hlo_s"] > 0
+    assert t["module_s"] == {} and t["scope_s"] == {}
+    assert line["correct"] is True

@@ -73,8 +73,12 @@ def test_traced_run_reports_per_layer(bench, capsys):
     assert rc == 0
     line = json.loads(out.strip().splitlines()[-1])
     # the CPU has no device plane: the readers of device metrics find
-    # nothing and their metrics are left out, never reported as 0
-    assert set(line["metrics"]) == {"queue_wait_p95_ms", "mfu.serve"}
+    # nothing and their metrics are left out, never reported as 0; the
+    # program's spans, stamps and counters are read as on the chip
+    assert set(line["metrics"]) == {
+        "mfu.serve", "engine_queue_wait_p95_ms", "admit_ms_per_prefill.serve",
+        "retire_ms_per_step.serve", "host_syncs_per_step.serve",
+        "batch_per_step.serve"}
     assert line["device"]["window_s"] > 0
     assert "breakdown" in line and line["correct"] is True
 
